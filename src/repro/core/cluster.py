@@ -440,7 +440,7 @@ class ClusterSpec:
         if art:
             spec = cls.from_json(art)
         else:
-            spec = cls.of(getattr(args, "system", None) or "paper")
+            spec = cls.of(getattr(args, "system", None) or device_system())
         phi = parse_phi_table(getattr(args, "phi", None))
         sigma = parse_sigma_table(getattr(args, "sigma", None))
         topo_s = getattr(args, "topology", None)
@@ -573,17 +573,33 @@ _NAMED_SYSTEMS = {"paper": PAPER_V100_CLUSTER, "tpu": TPU_V5E_POD,
                   "host": cpu_host_model()}
 
 
+def device_system() -> str:
+    """The preset that models the attached device: a TPU v5e is "tpu", the
+    CPU is "host". Any other device has no preset and is an error, never a
+    silent default (jax is imported here, at call time, not at import)."""
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return "host"
+    if dev.platform == "tpu" and dev.device_kind == "TPU v5 lite":
+        return "tpu"
+    raise ValueError(f"no --system preset models a {dev.platform} device "
+                     f"({dev.device_kind!r}); pass --system or --cluster")
+
+
 # ---------------------------------------------------------------------------
 # CLI wiring (the one home for the flags sweep/autotune used to copy-paste)
 # ---------------------------------------------------------------------------
 
-def add_cluster_args(ap, default_system: str = "paper") -> None:
+def add_cluster_args(ap, default_system: str | None = "paper") -> None:
     """Attach the machine-description flags to an argparse parser; pair
-    with ``ClusterSpec.from_cli_args``."""
+    with ``ClusterSpec.from_cli_args``. ``default_system=None`` makes the
+    preset follow the attached device (``device_system``)."""
     g = ap.add_argument_group("cluster (machine description)")
     g.add_argument("--system", default=default_system,
                    choices=sorted(_NAMED_SYSTEMS),
-                   help="named cluster preset (hardware.py α–β models)")
+                   help="named cluster preset (hardware.py α–β models); "
+                        f"default: {default_system or 'the attached device'}")
     g.add_argument("--cluster", default=None, metavar="JSON",
                    help="fitted ClusterSpec artifact (e.g. experiments/"
                         "cluster_fit.json); overrides --system")

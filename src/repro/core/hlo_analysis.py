@@ -181,8 +181,6 @@ class CellCost:
 
 def cost_of(compiled, mesh_shape: dict[str, int]) -> CellCost:
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):   # older jax wraps the dict in a list
-        ca = ca[0] if ca else {}
     ma = compiled.memory_analysis()
     colls = parse_collectives(compiled.as_text(), mesh_shape)
     return CellCost(

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+from ..kernels.util import SCOPED_VMEM_BYTES
 from .hlo_analysis import CellCost
 
 
@@ -25,7 +26,8 @@ class HardwareSpec:
     ici_bw: float = 50e9             # B/s per link (per mesh axis)
     dci_bw: float = 25e9             # B/s per chip across pods (assumption)
     hbm_bytes: float = 16e9          # v5e HBM capacity
-    vmem_bytes: float = 128 * 2**20  # on-chip VMEM a Pallas program tiles for
+    vmem_bytes: float = SCOPED_VMEM_BYTES  # VMEM the compiler lets one
+    #                                        Pallas program use (of 128 MiB)
     mxu: int = 128                   # systolic array edge (MXU 128×128)
 
     @classmethod

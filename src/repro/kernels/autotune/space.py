@@ -11,8 +11,11 @@ Per kernel the knobs are the block sizes its wrapper already exposes:
 
 Two analytic filters, both read off ``HardwareSpec.from_cluster``:
 
-* **VMEM capacity** — a candidate whose per-program working set exceeds
-  ``VMEM_FRACTION`` of ``hw.vmem_bytes`` cannot be scheduled; reject.
+* **VMEM capacity** — a candidate whose per-program working set (blocks
+  double-buffered, as the Pallas pipeline holds them) exceeds
+  ``VMEM_FRACTION`` of ``hw.vmem_bytes`` — the scoped limit the compiler
+  enforces per kernel, not the chip's whole VMEM — cannot be compiled;
+  reject.
 * **Roofline knee** — predicted time is
   ``max(compute_s, memory_s) + programs · DISPATCH_S`` with MXU utilization
   ``min(block, hw.mxu)/hw.mxu`` scaling the compute term; candidates worse
@@ -26,7 +29,8 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-from ..util import largest_divisor, resolve_block_rows
+from ..util import (conv_row_block, conv_vmem_bytes, largest_divisor,
+                    resolve_block_rows)
 
 DISPATCH_S = 2e-6          # per-program launch overhead charged to the grid
 VMEM_FRACTION = 0.9        # usable fraction of hw.vmem_bytes per program
@@ -118,19 +122,22 @@ def _conv_candidates(dims, hw):
     B, H, W, C, F = (dims[k] for k in "BHWCF")
     kh, kw, sh, sw, e = dims["kh"], dims["kw"], dims["sh"], dims["sw"], dims["e"]
     Ho, Wo = -(-H // sh), -(-W // sw)
-    Hp, Wp = (kh - 1) + sh * Ho, (kw - 1) + sw * Wo
+    Wp = (kw - 1) + sw * Wo
     flops = 2.0 * B * Ho * Wo * kh * kw * C * F
     out = []
     dbf = largest_divisor(F, DEFAULT_BLOCKS["conv2d_gemm"]["block_f"])
     for bf in _resolved(_BLOCK_CHOICES["conv2d_gemm"], F, dbf):
-        programs = B * (F // bf)
-        # x tile is re-read once per filter block; weights/output are read
-        # exactly once regardless of bf — larger bf ⇒ less x traffic.
-        traffic = (programs * Hp * Wp * C * e          # x tiles
+        # the kernel's own row block: the most output rows that fit
+        tile = dict(Wp=Wp, Wo=Wo, C=C, kh=kh, kw=kw, sh=sh, bf=bf, e=e)
+        bh = conv_row_block(Ho, **tile)
+        programs = B * (Ho // bh) * (F // bf)
+        # each row window is re-read once per filter block; weights/output
+        # are read once regardless of bf — larger bf ⇒ less x traffic.
+        rows_in = (kh - 1) + sh * bh
+        traffic = (programs * rows_in * Wp * C * e     # x windows
                    + B * kh * kw * C * F * e           # weight blocks
                    + B * Ho * Wo * F * e)              # output
-        vmem = (Hp * Wp * C * e + kh * kw * C * bf * e
-                + Ho * Wo * bf * 4 + Ho * Wo * bf * e)
+        vmem = conv_vmem_bytes(bh, **tile)
         compute = flops / (hw.peak_bf16 * _util(bf, hw.mxu))
         out.append(_mk("conv2d_gemm", {"block_f": bf}, compute,
                        traffic / hw.hbm_bw, vmem, programs, bf == dbf))
@@ -154,7 +161,7 @@ def _flash_candidates(dims, hw):
             # each program streams the (causal-truncated) KV; q/out once
             traffic = (programs * kv_frac * 2 * S * D * e
                        + 2 * B * Hh * S * D * e)
-            vmem = (rq * D * e + 2 * S * D * e          # q block + full K,V
+            vmem = (2 * (2 * rq * D * e + 2 * S * D * e)  # q, out, K, V ×2
                     + rq * rk * 4 + rq * D * 4)         # logits + fp32 acc
             compute = flops / (hw.peak_bf16
                                * _util(min(rq, rk), hw.mxu) * _util(D, hw.mxu))
@@ -177,7 +184,7 @@ def _rmsnorm_candidates(dims, hw):
         # memory-bound VPU op: rows in + rows out (+ per-program scale
         # re-read); padding waste shows up as Rp > R traffic.
         traffic = 2 * Rp * D * e + programs * D * e
-        vmem = 2 * br * D * e + br * D * 4
+        vmem = 2 * (2 * br * D * e) + br * D * 4     # in, out ×2 + fp32
         compute = 3.0 * Rp * D / hw.peak_bf16           # negligible by design
         out.append(_mk("rmsnorm", {"block_rows": br}, compute,
                        traffic / hw.hbm_bw, vmem, programs, br == dbr))

@@ -40,14 +40,6 @@ SMOKE_SHAPES = (
 SHAPE_SETS = {"full": DEFAULT_SHAPES, "smoke": SMOKE_SHAPES}
 
 
-def _detect_backend() -> str:
-    import jax
-    try:
-        return jax.default_backend()
-    except Exception:
-        return "cpu"
-
-
 def tune_kernels(cluster, *, shapes="full", path: str | None = None,
                  iters: int = 3, warmup: int = 1, slack: float = 2.0,
                  top_k: int = 4, backend: str | None = None,
@@ -65,7 +57,8 @@ def tune_kernels(cluster, *, shapes="full", path: str | None = None,
     if isinstance(shapes, str):
         shapes = SHAPE_SETS[shapes]
     if backend is None:
-        backend = _detect_backend()
+        import jax
+        backend = jax.default_backend()
     cache = KernelTuneCache(fingerprint=cluster.fingerprint(),
                             backend=backend, cluster_name=cluster.name)
     for kernel, dims in shapes:

@@ -39,10 +39,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int,
 
     def body(ki, carry):
         m, s, o = carry
-        k = pl.load(k_ref, (pl.dslice(ki * bk, bk), slice(None))
-                    ).astype(jnp.float32)                  # (BK, D)
-        v = pl.load(v_ref, (pl.dslice(ki * bk, bk), slice(None))
-                    ).astype(jnp.float32)
+        k = k_ref[pl.ds(ki * bk, bk), :].astype(jnp.float32)  # (BK, D)
+        v = v_ref[pl.ds(ki * bk, bk), :].astype(jnp.float32)
         logits = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (BQ,BK)
         if causal:
             qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)

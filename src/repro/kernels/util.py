@@ -17,6 +17,41 @@ import math
 # to the requested block (and wasting the pad rows) is cheaper
 MIN_BLOCK_ROWS = 16
 
+# VMEM one Pallas program may use on a TPU v5e: the compiler's default scoped
+# limit, not the chip's 128 MiB (a kernel whose double-buffered blocks need
+# more is refused with RESOURCE_EXHAUSTED at compile time)
+SCOPED_VMEM_BYTES = 16 * 2**20
+
+
+def _tile(n: int, m: int) -> int:
+    return -(-int(n) // m) * m
+
+
+def conv_vmem_bytes(bh: int, *, Wp: int, Wo: int, C: int, kh: int, kw: int,
+                    sh: int, bf: int, e: int) -> int:
+    """VMEM an implicit-GEMM conv program holds for ``bh`` output rows:
+    double-buffered input window, filter block and output block, plus the
+    fp32 accumulator and one shifted patch. Minor dims pad to the (8, 128)
+    vreg tile, so a 3-channel stem row costs as much as a 128-channel one."""
+    rows_in = (kh - 1) + sh * bh
+    x = rows_in * _tile(Wp, 8) * _tile(C, 128) * e
+    w = kh * kw * _tile(C, 8) * _tile(bf, 128) * e
+    out = bh * _tile(Wo, 8) * _tile(bf, 128) * e
+    acc = bh * _tile(Wo, 8) * _tile(bf, 128) * 4
+    patch = sh * bh * _tile(Wp, 8) * _tile(C, 128) * 4
+    return 2 * (x + w + out) + acc + patch
+
+
+def conv_row_block(Ho: int, budget: int = SCOPED_VMEM_BYTES // 2,
+                   **dims) -> int:
+    """Output rows per conv program: the largest divisor of ``Ho`` whose
+    working set (``conv_vmem_bytes``) fits ``budget``. Half the scoped limit
+    by default, leaving the rest to the compiler's own scratch."""
+    for bh in range(Ho, 0, -1):
+        if Ho % bh == 0 and conv_vmem_bytes(bh, **dims) <= budget:
+            return bh
+    return 1
+
 
 def largest_divisor(n: int, cap: int) -> int:
     """Largest divisor of ``n`` that is ≤ ``cap`` (O(√n); cap clamped to

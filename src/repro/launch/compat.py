@@ -1,64 +1,28 @@
-"""jax version-compat shims (mesh construction, shard_map).
+"""Mesh and shard_map helpers with the repo's fixed choices.
 
-Newer jax exposes ``jax.sharding.AxisType`` and ``jax.make_mesh(...,
-axis_types=...)``; the pinned CPU image (jax 0.4.37) has neither. Every
-mesh in this repo wants plain ``Auto`` axes, so the shim passes
-``axis_types=(AxisType.Auto, ...)`` exactly when the running jax defines
-``AxisType`` and builds an identical Auto-axis mesh otherwise (pre-AxisType
-jax has no explicit/auto distinction — Auto is the only behaviour).
-Similarly ``jax.shard_map`` (with ``check_vma``) only exists on newer jax;
-older versions spell it ``jax.experimental.shard_map.shard_map`` (with
-``check_rep``).
-
-Use ``compat.make_mesh(shape, axes)`` / ``compat.shard_map(...)``
-everywhere instead of calling the jax originals with version-specific
-arguments.
+Every mesh in this repo wants plain ``Auto`` axes, and every shard_map takes
+its arguments by keyword. Use ``compat.make_mesh(shape, axes)`` /
+``compat.shard_map(...)`` instead of repeating those choices at each call
+site.
 """
 from __future__ import annotations
 
 from typing import Sequence
 
 import jax
-
-
-def axis_type_kwargs(n_axes: int) -> dict:
-    """``{"axis_types": (AxisType.Auto,) * n}`` when this jax has AxisType,
-    ``{}`` otherwise (older jax: every axis is implicitly Auto)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str],
               *, devices=None) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types on any jax version."""
-    kwargs = axis_type_kwargs(len(axes))
-    if devices is not None:
-        kwargs["devices"] = devices
-    return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)
-
-
-def axis_size(axis_name: str):
-    """``jax.lax.axis_size`` on any jax version (old spelling: psum(1, axis),
-    which jax folds to a static value inside shard_map/pmap)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """``jax.make_mesh`` with Auto axis types."""
+    kwargs = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes), **kwargs)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """``jax.shard_map`` on any jax version.
-
-    Newer jax: top-level ``jax.shard_map`` with ``check_vma``. Older jax:
-    ``jax.experimental.shard_map.shard_map`` where the same knob is named
-    ``check_rep``.
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kwargs = {} if check_vma is None else {"check_rep": check_vma}
-    return _shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
+    """``jax.shard_map``; ``check_vma=None`` keeps jax's default."""
+    kwargs = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)

@@ -27,6 +27,7 @@ from ..nn.module import ShardingCtx, tree_init
 from ..parallel.strategies import make_rules
 from ..serve import Engine, ServeConfig, TrafficModel
 from .build import build_model
+from .cache import use_compile_cache
 from .mesh import make_host_mesh
 
 
@@ -97,8 +98,9 @@ def main(argv=None) -> None:
     ap.add_argument("--json-out", default=None,
                     help="write the report summary as JSON")
     from ..core.cluster import add_cluster_args
-    add_cluster_args(ap, default_system="host")
+    add_cluster_args(ap, default_system=None)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if cfg.family != "lm":

@@ -12,6 +12,8 @@ from __future__ import annotations
 
 import argparse
 import time
+from dataclasses import dataclass
+from typing import Any
 
 import jax
 
@@ -25,9 +27,11 @@ from ..models.vlm import VLMConfig
 from ..nn.module import ShardingCtx, tree_init
 from ..optim.optimizers import OptimizerConfig
 from ..parallel.strategies import make_rules
+from ..runtime.elastic import state_shardings
 from ..runtime.fault_tolerance import run_with_recovery
 from ..training.steps import make_train_step, train_state_spec
 from .build import build_model
+from .cache import use_compile_cache
 from .mesh import make_host_mesh
 
 
@@ -101,7 +105,7 @@ def _main_elastic(args, cfg, mc, model) -> None:
               f"(checkpoint already at --steps)")
 
 
-def main(argv=None) -> None:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -138,22 +142,42 @@ def main(argv=None) -> None:
     ap.add_argument("--straggler-patience", type=int, default=3,
                     help="--elastic: consecutive StragglerAlerts before the "
                          "loop checkpoints and remeshes around the slow host")
-    # machine description for --strategy auto (default: the host box;
-    # --cluster takes a fitted experiments/cluster_fit.json artifact)
+    # machine description for --strategy auto (default: the preset of the
+    # attached device; --cluster takes a fitted experiments/cluster_fit.json
+    # artifact)
     from ..core.cluster import add_cluster_args
-    add_cluster_args(ap, default_system="host")
-    args = ap.parse_args(argv)
+    add_cluster_args(ap, default_system=None)
+    return ap.parse_args(argv)
 
+
+@dataclass
+class Trainer:
+    """What ``main`` assembles before its loop: the model, the sharding
+    context, the jitted step (state donated), the initial state placed per
+    the strategy's rules, the loader and the checkpointer."""
+
+    model: Any
+    mesh: Any
+    ctx: ShardingCtx
+    opt: OptimizerConfig
+    step: Any
+    state: Any
+    shardings: Any
+    loader: ShardedLoader
+    ckpt: Checkpointer
+
+
+def build_trainer(args, mesh=None) -> Trainer:
+    """Assemble the run the flags describe. ``mesh`` overrides the mesh of a
+    named strategy (default: every device, ``make_host_mesh``)."""
     cfg = get_config(args.arch)
     mc = cfg.smoke_model if args.smoke else cfg.model
     model = build_model(cfg, smoke=args.smoke)
-    if args.elastic:
-        return _main_elastic(args, cfg, mc, model)
     strategy, plan = args.strategy, None
     if strategy == "auto":
         # oracle-in-the-loop: tune (strategy, mesh split, memory switches)
-        # for the machine the cluster flags describe (default: this box),
-        # then deploy the plan (DESIGN.md §8/§11)
+        # for the machine the cluster flags describe (default: the attached
+        # device), then deploy the plan (DESIGN.md §8/§11)
         from ..core.autotune import autotune, stats_for_model
         from ..core.cluster import ClusterSpec
         from ..core.oracle import TimeModel
@@ -177,7 +201,7 @@ def main(argv=None) -> None:
         mesh = make_host_mesh(model=plan.p2 if n % plan.p2 == 0 else None)
         opt = OptimizerConfig(lr=args.lr, zero1=plan.zero1)
     else:
-        mesh = make_host_mesh()
+        mesh = mesh or make_host_mesh()
         opt = OptimizerConfig(lr=args.lr, zero1=strategy != "pipeline")
     rules = make_rules(strategy)
     ctx = ShardingCtx(mesh, rules)
@@ -221,13 +245,28 @@ def main(argv=None) -> None:
     else:
         step = jax.jit(make_train_step(model, opt, ctx, accum=args.accum,
                                        **fwd_kw), donate_argnums=(0,))
-    sspec = train_state_spec(model, opt)
-    state = tree_init(sspec, jax.random.PRNGKey(args.seed))
-
-    dcfg = data_config_for(mc, args.batch, args.seq, args.seed)
-    loader = ShardedLoader(dcfg, mesh)
+    # the state starts out placed per the rules (params and step on the
+    # strategy's table, optimizer state on ZeRO-1's), not on one device
+    shardings = state_shardings(model, opt, mesh, rules)
+    state = jax.device_put(
+        tree_init(train_state_spec(model, opt), jax.random.PRNGKey(args.seed)),
+        shardings)
+    loader = ShardedLoader(data_config_for(mc, args.batch, args.seq,
+                                           args.seed), mesh)
     ckpt = Checkpointer(f"{args.ckpt_dir}/{args.arch}",
                         config_tag=config_hash((args.arch, args.smoke)))
+    return Trainer(model, mesh, ctx, opt, step, state, shardings, loader,
+                   ckpt)
+
+
+def main(argv=None) -> None:
+    args = parse_args(argv)
+    use_compile_cache()
+    if args.elastic:
+        cfg = get_config(args.arch)
+        mc = cfg.smoke_model if args.smoke else cfg.model
+        return _main_elastic(args, cfg, mc, build_model(cfg, smoke=args.smoke))
+    t = build_trainer(args)
 
     t_start = time.time()
     losses = []
@@ -239,13 +278,13 @@ def main(argv=None) -> None:
                   f"grad_norm {float(m['grad_norm']):.3f} "
                   f"({(time.time()-t_start):.1f}s)", flush=True)
 
-    start = ckpt.latest_step() or 0
+    state, start = t.state, t.ckpt.latest_step() or 0
     if start:
-        state, start = ckpt.restore(state)
+        state, start = t.ckpt.restore(state, shardings=t.shardings)
         print(f"resumed from step {start}")
     state, final = run_with_recovery(
-        step, state, loader, ckpt, n_steps=args.steps, start_step=start,
-        ckpt_every=args.ckpt_every, on_metrics=on_metrics)
+        t.step, state, t.loader, t.ckpt, n_steps=args.steps,
+        start_step=start, ckpt_every=args.ckpt_every, on_metrics=on_metrics)
     if losses:
         print(f"done at step {final}; loss {losses[0]:.4f} → {losses[-1]:.4f}")
     else:   # resumed at/past --steps: zero new steps this run

@@ -10,8 +10,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..launch.compat import axis_size
-
 
 def quantize_int8(g, residual=None):
     """→ (q int8, scale, new_residual). Error feedback keeps the quantization
@@ -35,7 +33,7 @@ def compressed_mean(tree, axis_name: str, residuals=None):
     Accumulates in int32 (no overflow below ~2^23 summands), then rescales.
     Returns (mean_tree, residual_tree).
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
 
     def one(g, r):
         gf = g.astype(jnp.float32) + (r if r is not None else 0.0)

@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..launch.compat import axis_size, shard_map
+from ..launch.compat import shard_map
 from ..nn.layers import Conv
 from ..nn.module import NULL_CTX, ShardingCtx, spec_to_pspec
 
@@ -58,7 +58,7 @@ def halo_exchange(x, halo: int | tuple[int, int], axis: str):
     if x.shape[1] < max(lo, hi):
         raise ValueError(
             f"shard too thin for the halo: H_local={x.shape[1]} < "
-            f"halo={max(lo, hi)} (p={axis_size(axis)}) — one-hop neighbour "
+            f"halo={max(lo, hi)} (p={jax.lax.axis_size(axis)}) — one-hop neighbour "
             f"exchange cannot serve this kernel; use fewer spatial shards")
     from_up, from_down = _exchange(x, lo, hi, axis)
     return jnp.concatenate([from_up, x, from_down], axis=1)
@@ -68,7 +68,7 @@ def _exchange(x, lo: int, hi: int, axis: str):
     """The two ppermute transfers: returns (rows from up, rows from down),
     zero-filled at the global edges. Issued before any compute that uses
     them so the DMA can overlap the interior convolution."""
-    p = axis_size(axis)
+    p = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     empty = x[:, :0]
     from_up = from_down = empty

@@ -64,8 +64,8 @@ def stack_stage_bounds(layer_params_stacked, bounds):
         raise ValueError(f"empty stage in bounds {bounds}")
     m = max(counts)
     # one gather per leaf, NOT concat-of-slices: under jit, XLA's SPMD
-    # partitioner miscompiles a concat/stack of slices feeding a shard_map
-    # with P(stage) in_specs (jax 0.4.37 — values silently wrong); a single
+    # partitioner has miscompiled a concat/stack of slices feeding a
+    # shard_map with P(stage) in_specs (values silently wrong); a single
     # take lowers to a clean gather that reshards correctly. Padded slots
     # clamp to the stage's last layer; the mask keeps their cotangents at
     # exactly zero, so the duplicated layer sees no spurious gradient.
@@ -83,8 +83,8 @@ def stack_virtual_stage_bounds(layer_params_stacked, bounds,
 
     Chunk j = q·p + r of the contiguous DP partition goes to rank r,
     virtual slot q — the round-robin assignment the interleaved schedule's
-    ring permute expects. Same single-gather restack (and the same jax
-    0.4.37 concat-of-slices caveat) as ``stack_stage_bounds``.
+    ring permute expects. Same single-gather restack (and the same
+    concat-of-slices caveat) as ``stack_stage_bounds``.
     """
     bounds = tuple(int(b) for b in bounds)
     p, v = int(n_stages), int(virtual_stages)

@@ -289,6 +289,24 @@ def test_from_cli_args_is_the_one_wiring():
     assert bare.phi is None and bare.sigma is None and bare.topology is None
 
 
+@pytest.mark.parametrize("platform,kind,want", [
+    ("cpu", "cpu", "host"), ("tpu", "TPU v5 lite", "tpu"),
+    ("tpu", "TPU v4", None), ("gpu", "NVIDIA H100", None)])
+def test_default_system_follows_the_device(monkeypatch, platform, kind, want):
+    """With no --system (the train/serve default) the preset is the
+    attached device's; a device no preset models is an error."""
+    import types
+    import jax
+    dev = types.SimpleNamespace(platform=platform, device_kind=kind)
+    monkeypatch.setattr(jax, "devices", lambda: [dev])
+    args = _parse([], default_system=None)
+    if want is None:
+        with pytest.raises(ValueError, match="no --system preset"):
+            ClusterSpec.from_cli_args(args)
+    else:
+        assert ClusterSpec.from_cli_args(args) == ClusterSpec.of(want)
+
+
 def test_session_tune_uses_the_sessions_stats():
     """A session seq override must reach tune(): the plan ranks exactly
     the stats project()/sweep() report, not shape.seq_len recomputes."""

@@ -1,11 +1,8 @@
-"""jax version-compat shim (launch/compat.py).
-
-The shim must build identical Auto-axis meshes whether or not the running
-jax exposes ``jax.sharding.AxisType`` — both branches are exercised here by
-stubbing the attribute in or out, plus a functional build on the real jax
-(whichever branch this image takes).
-"""
+"""Mesh and shard_map helpers (launch/compat.py)."""
 import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.launch import compat
 
@@ -14,65 +11,20 @@ def test_make_mesh_works_on_this_jax():
     mesh = compat.make_mesh((1, 1), ("data", "model"))
     assert mesh.axis_names == ("data", "model")
     assert dict(mesh.shape) == {"data": 1, "model": 1}
-
-
-def test_axis_type_kwargs_without_axistype(monkeypatch):
-    monkeypatch.delattr(jax.sharding, "AxisType", raising=False)
-    assert compat.axis_type_kwargs(2) == {}
-
-
-def test_axis_type_kwargs_with_axistype(monkeypatch):
-    class FakeAxisType:
-        Auto = "auto-sentinel"
-
-    monkeypatch.setattr(jax.sharding, "AxisType", FakeAxisType,
-                        raising=False)
-    kw = compat.axis_type_kwargs(3)
-    assert kw == {"axis_types": ("auto-sentinel",) * 3}
-
-
-def test_make_mesh_passes_axis_types_only_when_supported(monkeypatch):
-    """Whatever axis_type_kwargs yields is forwarded verbatim to
-    jax.make_mesh — the shim never hardcodes a branch."""
-    seen = {}
-
-    def fake_make_mesh(shape, axes, **kwargs):
-        seen.update(kwargs, shape=shape, axes=axes)
-        return "mesh-sentinel"
-
-    monkeypatch.setattr(jax, "make_mesh", fake_make_mesh)
-    monkeypatch.delattr(jax.sharding, "AxisType", raising=False)
-    assert compat.make_mesh((2, 4), ("data", "model")) == "mesh-sentinel"
-    assert seen == {"shape": (2, 4), "axes": ("data", "model")}
-
-    class FakeAxisType:
-        Auto = "auto-sentinel"
-
-    monkeypatch.setattr(jax.sharding, "AxisType", FakeAxisType,
-                        raising=False)
-    seen.clear()
-    compat.make_mesh((2, 4), ("data", "model"))
-    assert seen["axis_types"] == ("auto-sentinel", "auto-sentinel")
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
 
 
 def test_axis_size_matches_mesh_axis():
-    """compat.axis_size inside shard_map returns the mesh axis extent."""
-    import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
+    """jax.lax.axis_size inside compat.shard_map returns the mesh extent."""
     mesh = compat.make_mesh((1, 1), ("data", "model"))
     out = compat.shard_map(
-        lambda x: x * compat.axis_size("data"),
+        lambda x: x * jax.lax.axis_size("data"),
         mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False,
     )(jnp.ones(()))
     assert float(out) == 1.0
 
 
 def test_shard_map_runs_on_this_jax():
-    import jax.numpy as jnp
-    import numpy as np
-    from jax.sharding import PartitionSpec as P
-
     mesh = compat.make_mesh((1, 1), ("data", "model"))
     fn = compat.shard_map(lambda x: x + 1, mesh=mesh,
                           in_specs=P(), out_specs=P())

@@ -135,9 +135,16 @@ def test_serving_oracle_winner_measured():
 
 @pytest.mark.slow
 def test_oracle_validation_harness():
-    run_check("oracle_validation", retries=1)
+    run_check("oracle_validation")
 
 
 @pytest.mark.slow
 def test_compressed_gradient_allreduce():
     run_check("compressed_allreduce")
+
+
+@pytest.mark.slow
+def test_chip_smoke_sharded_phase():
+    """The four-chip phase of chip_smoke.py on virtual devices: the trainer
+    places state per the rules, and ds/df match one device."""
+    run_check("chip_smoke_sharded")

@@ -226,10 +226,11 @@ def test_tuned_block_reaches_pallas_call(monkeypatch):
     ctx = ShardingCtx(mesh=None, rules=rules, use_pallas=True,
                       kernel_tiles=tiles)
     y = conv.apply(params, x, ctx)
-    assert seen["grid"] == (1, 16 // 4)         # tuned block_f=4 deployed
+    # grid = (batch, output-row blocks, F / block_f); 8 rows fit one block
+    assert seen["grid"] == (1, 1, 16 // 4)      # tuned block_f=4 deployed
     ctx0 = ShardingCtx(mesh=None, rules=rules, use_pallas=True)
     y0 = conv.apply(params, x, ctx0)
-    assert seen["grid"] == (1, 1)               # default 128 → divisor 16
+    assert seen["grid"] == (1, 1, 1)            # default 128 → divisor 16
     np.testing.assert_allclose(np.asarray(y), np.asarray(y0),
                                rtol=1e-5, atol=1e-5)
 

@@ -150,3 +150,66 @@ def test_rmsnorm_prime_rows_pad_to_block(key, R):
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(rmsnorm_ref(x, sc)),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("strides,pad_h", [((1, 1), True), ((2, 2), True),
+                                           ((1, 1), False)])
+def test_conv2d_gemm_grad_matches_xla(key, strides, pad_h):
+    """The kernel's custom VJP (the equivalent XLA conv's VJP) gives the
+    gradients of the plain convolution, so a train step can run it."""
+    x = jax.random.normal(key, (2, 12, 10, 8))
+    w = jax.random.normal(jax.random.fold_in(key, 1), (3, 3, 8, 16)) * 0.1
+
+    def ref(a, b):
+        if pad_h:
+            return conv2d_ref(a, b, strides=strides)
+        dn = jax.lax.conv_dimension_numbers(a.shape, b.shape,
+                                            ("NHWC", "HWIO", "NHWC"))
+        return jax.lax.conv_general_dilated(a, b, (1, 1), ((0, 0), (1, 1)),
+                                            dimension_numbers=dn)
+
+    def loss(f):
+        return lambda a, b: jnp.sum(f(a, b) ** 2)
+
+    got = jax.grad(loss(lambda a, b: conv2d_gemm(
+        a, b, strides=strides, pad_h=pad_h, interpret=True)), (0, 1))(x, w)
+    want = jax.grad(loss(ref), (0, 1))(x, w)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=1e-4, atol=1e-4)
+
+
+# (Ho, Wp, Wo, C, kh, sh, F) of ResNet-50's kernel convs at 224²
+RESNET50_KERNEL_CONVS = [(112, 229, 112, 3, 7, 2, 64), (56, 58, 56, 64, 3, 1, 64),
+                         (28, 57, 28, 128, 3, 2, 128),
+                         (14, 16, 14, 256, 3, 1, 256), (7, 9, 7, 512, 3, 1, 512)]
+
+
+@pytest.mark.parametrize("Ho,Wp,Wo,C,kh,sh,F", RESNET50_KERNEL_CONVS)
+def test_conv_row_block_fits_scoped_vmem(Ho, Wp, Wo, C, kh, sh, F):
+    """Each program's working set stays inside the compiler's scoped VMEM
+    limit; the 3-channel stem, whose rows pad to 128 lanes, is row-blocked
+    instead of holding the whole 229² image."""
+    from repro.kernels.util import (SCOPED_VMEM_BYTES, conv_row_block,
+                                    conv_vmem_bytes)
+    dims = dict(Wp=Wp, Wo=Wo, C=C, kh=kh, kw=kh, sh=sh, bf=min(F, 128), e=4)
+    bh = conv_row_block(Ho, **dims)
+    assert Ho % bh == 0
+    assert conv_vmem_bytes(bh, **dims) <= SCOPED_VMEM_BYTES // 2
+    if C == 3:
+        assert bh < Ho
+
+
+def test_resnet_pallas_sites(key):
+    """With use_pallas, the stem and every bottleneck's 3×3 conv (strided
+    or not) run on the kernel; the 1×1 convs stay on XLA."""
+    from repro.models.cnn import ResNet, ResNetConfig
+    from repro.nn.module import ShardingCtx, tree_abstract
+    from repro.parallel.strategies import make_rules
+    cfg = ResNetConfig("tiny", (2, 1, 1, 1), n_classes=10)
+    model = ResNet(cfg)
+    ctx = ShardingCtx(None, make_rules("data"), use_pallas=True)
+    jaxpr = jax.make_jaxpr(lambda p, v: model.apply(p, v, ctx))(
+        tree_abstract(model.params_spec()),
+        jax.ShapeDtypeStruct((1, 32, 32, 3), jnp.float32))
+    assert str(jaxpr).count("pallas_call") == 1 + sum(cfg.stage_sizes)

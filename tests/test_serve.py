@@ -62,7 +62,10 @@ def test_paged_vs_dense_exact(key):
             + jnp.arange(C // geo.bspan)[None]
         pool = scatter_blocks(pool, tables, view, jidx)
         assert float(jnp.max(jnp.abs(lgp - lgr))) == 0.0
-        assert float(jnp.max(jnp.abs(lgr - full[:, k * C:(k + 1) * C]))) == 0.0
+        # decode vs the full forward: two different programs, which XLA may
+        # fuse and reassociate differently — float32 rounding, not a bug
+        assert float(jnp.max(jnp.abs(lgr - full[:, k * C:(k + 1) * C]))) \
+            <= 1e-5
         assert max_abs_diff(pool, tables, dense, geo, (k + 1) * C) == 0.0
 
 
