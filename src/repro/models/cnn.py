@@ -111,6 +111,13 @@ class ResNet:
                 in_ch = mid * 4
         return blocks
 
+    def _head(self):
+        # the last stage's output width: 2048 at width 64 over four stages
+        c = self.cfg
+        return Dense(c.width * 2 ** (len(c.stage_sizes) - 1) * 4, c.n_classes,
+                     use_bias=True, in_axis="mlp", out_axis="vocab",
+                     dtype=c.dtype)
+
     def params_spec(self):
         c = self.cfg
         spec = {
@@ -118,22 +125,26 @@ class ResNet:
                              use_bias=False, dtype=c.dtype).params_spec(),
             "bn_stem": BatchNorm(c.width).params_spec(),
             "blocks": [b.params_spec() for b in self._blocks()],
-            "head": Dense(512 * 4, c.n_classes, use_bias=True, in_axis="mlp",
-                          out_axis="vocab", dtype=c.dtype).params_spec(),
+            "head": self._head().params_spec(),
         }
         return spec
 
     def apply(self, params, x, ctx: ShardingCtx = NULL_CTX, train=True):
+        """Scoped for the profiler: ``stem``, ``stage{s}/block{b}`` for each
+        bottleneck (both counted from 0), ``head``."""
         c = self.cfg
-        h = HaloConv(3, c.width, (7, 7), strides=(2, 2), use_bias=False,
-                     dtype=c.dtype).apply(params["stem"], x, ctx)
-        h = jax.nn.relu(BatchNorm(c.width).apply(params["bn_stem"], h, ctx, train))
-        h = max_pool(h, (3, 3), (2, 2), "SAME")
-        for i, b in enumerate(self._blocks()):
-            h = b.apply(params["blocks"][i], h, ctx, train)
-        h = global_avg_pool(h)
-        return Dense(512 * 4, c.n_classes, use_bias=True, in_axis="mlp",
-                     out_axis="vocab", dtype=c.dtype).apply(params["head"], h, ctx)
+        with jax.named_scope("stem"):
+            h = HaloConv(3, c.width, (7, 7), strides=(2, 2), use_bias=False,
+                         dtype=c.dtype).apply(params["stem"], x, ctx)
+            h = jax.nn.relu(BatchNorm(c.width).apply(params["bn_stem"], h,
+                                                     ctx, train))
+            h = max_pool(h, (3, 3), (2, 2), "SAME")
+        where = [(s, b) for s, n in enumerate(c.stage_sizes) for b in range(n)]
+        for (s, b), block, p in zip(where, self._blocks(), params["blocks"]):
+            with jax.named_scope(f"stage{s}"), jax.named_scope(f"block{b}"):
+                h = block.apply(p, h, ctx, train)
+        with jax.named_scope("head"):
+            return self._head().apply(params["head"], global_avg_pool(h), ctx)
 
     def loss_fn(self, params, batch, ctx: ShardingCtx = NULL_CTX, train=True):
         logits = self.apply(params, batch["images"], ctx, train)
@@ -285,7 +296,8 @@ class CosmoFlow:
 
 
 def _softmax_xent(logits, labels):
-    logits = logits.astype(jnp.float32)
-    lse = jax.scipy.special.logsumexp(logits, axis=-1)
-    picked = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
-    return jnp.mean(lse - picked)
+    with jax.named_scope("loss"):
+        logits = logits.astype(jnp.float32)
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
+        return jnp.mean(lse - picked)

@@ -142,17 +142,19 @@ class BatchNorm:
         }
 
     def apply(self, params, x, ctx: ShardingCtx = NULL_CTX, train: bool = True):
-        dtype = x.dtype
-        xf = x.astype(jnp.float32)
-        axes = tuple(range(x.ndim - 1))
-        mu = jnp.mean(xf, axis=axes)
-        var = jnp.mean(xf * xf, axis=axes) - mu * mu
-        if self.sync_axis is not None:
-            mu = jax.lax.pmean(mu, self.sync_axis)
-            var = jax.lax.pmean(var, self.sync_axis)
-        y = (xf - mu) * jax.lax.rsqrt(var + self.eps)
-        y = y * params["scale"].astype(jnp.float32) + params["bias"].astype(jnp.float32)
-        return y.astype(dtype)
+        with jax.named_scope("batchnorm"):
+            dtype = x.dtype
+            xf = x.astype(jnp.float32)
+            axes = tuple(range(x.ndim - 1))
+            mu = jnp.mean(xf, axis=axes)
+            var = jnp.mean(xf * xf, axis=axes) - mu * mu
+            if self.sync_axis is not None:
+                mu = jax.lax.pmean(mu, self.sync_axis)
+                var = jax.lax.pmean(var, self.sync_axis)
+            y = (xf - mu) * jax.lax.rsqrt(var + self.eps)
+            y = (y * params["scale"].astype(jnp.float32)
+                 + params["bias"].astype(jnp.float32))
+            return y.astype(dtype)
 
 
 # ---------------------------------------------------------------------------

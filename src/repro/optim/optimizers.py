@@ -71,7 +71,13 @@ def clip_by_global_norm(grads, max_norm: float):
 
 
 def apply_update(opt: OptimizerConfig, params, grads, state, step):
-    """Pure update: returns (new_params, new_state, metrics)."""
+    """Pure update: returns (new_params, new_state, metrics). Its operations,
+    the clip included, carry the ``optimizer`` scope in the compiled HLO."""
+    with jax.named_scope("optimizer"):
+        return _update(opt, params, grads, state, step)
+
+
+def _update(opt: OptimizerConfig, params, grads, state, step):
     grads, gnorm = clip_by_global_norm(grads, opt.grad_clip)
     count = step.astype(jnp.float32) + 1.0
 

@@ -115,8 +115,7 @@ def _grouped_costs(names: list[str], stats) -> list[float]:
 
 
 def _resnet_blocks(model, stats) -> list[PipeBlock]:
-    from ...models.cnn import BatchNorm, Dense, HaloConv, global_avg_pool, \
-        max_pool
+    from ...models.cnn import BatchNorm, HaloConv, global_avg_pool, max_pool
     from ...nn.module import NULL_CTX
     c = model.cfg
 
@@ -128,10 +127,8 @@ def _resnet_blocks(model, stats) -> list[PipeBlock]:
         return max_pool(h, (3, 3), (2, 2), "SAME")
 
     def head(params, x):
-        h = global_avg_pool(x)
-        return Dense(512 * 4, c.n_classes, use_bias=True, in_axis="mlp",
-                     out_axis="vocab", dtype=c.dtype).apply(
-                         params["head"], h, NULL_CTX)
+        return model._head().apply(params["head"], global_avg_pool(x),
+                                   NULL_CTX)
 
     names, applies = ["stem"], [stem]
     bottlenecks = model._blocks()

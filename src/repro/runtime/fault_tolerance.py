@@ -34,6 +34,7 @@ from dataclasses import dataclass
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..checkpoint.checkpointing import Checkpointer
 from ..nn.module import Rules, tree_shardings
@@ -141,6 +142,13 @@ def run_with_recovery(step_fn, state, loader, ckpt: Checkpointer, *,
     restore onto a NEW mesh, so they pass the state spec tree and the
     re-tuned plan's shardings; by default the live state is the skeleton
     and leaves land wherever ``device_put`` defaults.
+
+    Each step opens profiler spans, in order: ``train.batch`` (the
+    loader), ``train.dispatch`` (the call into ``step_fn``), ``train.wait``
+    (until the loss is ready), ``train.metrics`` (``on_metrics``), and
+    ``train.checkpoint`` around each save and restore. They cost about a
+    microsecond without an active ``jax.profiler`` session, and share the
+    device trace's clock with one.
     """
     timer = timer or StepTimer()
     step = start_step
@@ -156,12 +164,15 @@ def run_with_recovery(step_fn, state, loader, ckpt: Checkpointer, *,
         try:
             fake_dt = inject(step) if inject is not None else None
             t0 = time.perf_counter()
-            batch = loader.batch_at(step)
+            with TraceAnnotation("train.batch"):
+                batch = loader.batch_at(step)
             if step in fail_steps and step not in fired:
                 fired.add(step)
                 raise RuntimeError(f"injected node failure at step {step}")
-            state, metrics = step_fn(state, batch)
-            jax.block_until_ready(metrics["loss"])
+            with TraceAnnotation("train.dispatch"):
+                state, metrics = step_fn(state, batch)
+            with TraceAnnotation("train.wait"):
+                jax.block_until_ready(metrics["loss"])
             dt = fake_dt if fake_dt is not None else time.perf_counter() - t0
             escalate = None
             try:
@@ -176,18 +187,21 @@ def run_with_recovery(step_fn, state, loader, ckpt: Checkpointer, *,
                         and strikes >= straggler_patience:
                     escalate = e
             if on_metrics:
-                on_metrics(step, metrics)
+                with TraceAnnotation("train.metrics"):
+                    on_metrics(step, metrics)
             step += 1
             if escalate is not None:
                 # graceful: the state is intact, persist it before leaving
-                ckpt.wait()
-                ckpt.save(state, step)
+                with TraceAnnotation("train.checkpoint"):
+                    ckpt.wait()
+                    ckpt.save(state, step)
                 raise SliceLost(
                     step, cause="straggler",
                     reason=f"{strikes} consecutive stragglers "
                            f"(last: {escalate})")
             if step % ckpt_every == 0:
-                ckpt.save(state, step, blocking=not async_ckpt)
+                with TraceAnnotation("train.checkpoint"):
+                    ckpt.save(state, step, blocking=not async_ckpt)
         except (StragglerAlert, SliceLost):
             raise
         except Exception as e:  # noqa: BLE001 — restart path
@@ -204,11 +218,13 @@ def run_with_recovery(step_fn, state, loader, ckpt: Checkpointer, *,
             print(f"[recovery] {e!r} → restoring from "
                   f"{'step ' + str(latest) if latest is not None else 'init'}")
             if latest is not None:
-                state, step = ckpt.restore(
-                    skeleton if skeleton is not None else state,
-                    shardings=restore_shardings)
+                with TraceAnnotation("train.checkpoint"):
+                    state, step = ckpt.restore(
+                        skeleton if skeleton is not None else state,
+                        shardings=restore_shardings)
             else:
                 step = start_step
-    ckpt.wait()
-    ckpt.save(state, step)
+    with TraceAnnotation("train.checkpoint"):
+        ckpt.wait()
+        ckpt.save(state, step)
     return state, step
