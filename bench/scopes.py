@@ -9,7 +9,7 @@ opens ``train.batch``, ``train.dispatch``, ``train.wait``, ``train.metrics``
 and ``train.checkpoint`` spans on the host, on the device trace's clock.
 
 This extends ``bench/trace.py`` and changes none of its numbers: the same
-ops, window and per-op seconds, to which it adds
+ops, window, steps and per-op seconds, to which it adds
 
 - device seconds per scope path, and the three readings of the layers the
   scopes and spans mark: ``optimizer_ms``, ``bn_ms`` and ``loop_host_ms``;
@@ -17,7 +17,7 @@ ops, window and per-op seconds, to which it adds
   as ``trace.host_span_over`` names it;
 - a breakdown whose ops carry their scope path.
 
-    python3 -m bench.scopes TRACE.xplane.pb STEP_HLO.txt[.gz] --steps N
+    python3 -m bench.scopes TRACE.xplane.pb STEP_HLO.txt[.gz]
 """
 from __future__ import annotations
 
@@ -29,9 +29,8 @@ import sys
 from dataclasses import dataclass, field
 
 from bench import trace
-from bench.trace import Op, Span
+from bench.trace import TRAIN_PREFIX, Op, Span
 
-TRAIN_PREFIX = "train."
 OP_NAME = re.compile(r'%([\w.\-]+) = .*\bmetadata=\{[^}]*?op_name="([^"]*)"')
 WRAPPER = re.compile(r"^([\w\-]+)\((.*)\)$")
 
@@ -89,23 +88,6 @@ def under(path: str, scope: str) -> bool:
     return scope in path.split("/")
 
 
-def load_spans(path: str) -> list[Span]:
-    """The ``bench.*`` and ``train.*`` spans of a trace's host plane, in
-    order of their start."""
-    from jax.profiler import ProfileData
-    pd = ProfileData.from_file(path)
-    spans = []
-    for plane in pd.planes:
-        if plane.name != trace.HOST_PLANE:
-            continue
-        for line in plane.lines:
-            for ev in line.events:
-                if ev.name.startswith((trace.SPAN_PREFIX, TRAIN_PREFIX)):
-                    t0 = ev.start_ns * 1e-9
-                    spans.append(Span(ev.name, t0, t0 + ev.duration_ns * 1e-9))
-    return sorted(spans, key=lambda s: s.start)
-
-
 def gap_name(spans: list[Span], t0: float, t1: float) -> str:
     """What the host was doing through an idle gap (t0, t1): the
     ``train.*`` span that covers most of it; where none covers any of it,
@@ -138,17 +120,14 @@ def idle_gaps(ops: list[Op], spans: list[Span], red: trace.Reduction
 
 
 def loop_host_s(spans: list[Span]) -> float | None:
-    """Mean over consecutive steps of (end of the next step's
-    ``train.dispatch`` - end of this step's ``train.wait``): the host's
-    turn-around between steps, which a loop that blocks on each step's
-    loss puts on the chip's idle time. None without such a pair."""
-    turns, waited = [], None
-    for s in sorted(spans, key=lambda s: s.end):
-        if s.name == "train.wait":
-            waited = s.end
-        elif s.name == "train.dispatch" and waited is not None:
-            turns.append(s.end - waited)
-            waited = None
+    """Mean over consecutive steps of (start of step s+1's ``train.wait`` -
+    end of step s's): the host's own work per step, whatever order the loop
+    opens its spans in. A loop that blocks on each step's loss puts it on
+    the chip's idle time; one that dispatches ahead overlaps it with the
+    step in flight. None without two waits."""
+    waits = sorted((s for s in spans if s.name == "train.wait"),
+                   key=lambda s: s.start)
+    turns = [b.start - a.end for a, b in zip(waits, waits[1:])]
     return sum(turns) / len(turns) if turns else None
 
 
@@ -159,7 +138,7 @@ class Layers:
     red: trace.Reduction
     scopes: dict                      # op name -> scope path
     kinds: dict                       # op name -> kind (``trace.hlo_kinds``)
-    spans: list = field(default_factory=list)   # train.* spans of the window
+    spans: list = field(default_factory=list)   # train.* spans of the trace
     gaps: list = field(default_factory=list)    # (gap_name, seconds)
 
     def by_scope(self) -> dict:
@@ -185,16 +164,16 @@ class Layers:
         total = sum(self.red.by_op.values())
         return self.by_scope().get("", 0.0) / total if total else 0.0
 
-    def readings(self, steps: int) -> dict:
-        """Per traced step and per chip, in ms: ``optimizer_ms``, the ops
-        under ``optimizer``; ``bn_ms``, the ops under ``batchnorm`` that
-        neither are nor fuse a convolution (those count with the
+    def readings(self) -> dict:
+        """Per step of the window and per chip, in ms: ``optimizer_ms``,
+        the ops under ``optimizer``; ``bn_ms``, the ops under ``batchnorm``
+        that neither are nor fuse a convolution (those count with the
         convolutions); ``loop_host_ms`` (``loop_host_s``). A reading with
         nothing to read, as on a program without the scopes or spans, is
         left out."""
         out = {"optimizer_ms": self.seconds_under("optimizer"),
                "bn_ms": self.seconds_under("batchnorm", convolutions=False)}
-        out = {k: 1e3 * v / steps for k, v in out.items() if v > 0}
+        out = {k: 1e3 * v / self.red.steps for k, v in out.items() if v > 0}
         host = loop_host_s(self.spans)
         if host is not None:
             out["loop_host_ms"] = 1e3 * host
@@ -217,13 +196,11 @@ def reduce(path: str, hlo_text: str, n_devices: int | None = None) -> Layers:
     """``trace.reduce`` of a trace and its step's compiled HLO text, with
     the program's scopes and spans."""
     kinds = trace.hlo_kinds(hlo_text)
-    ops, bench_spans = trace.load(path, kinds)
-    red = trace.reduce_ops(ops, bench_spans, n_devices)
-    t0, t1 = red.window
-    spans = load_spans(path)
+    ops, spans, runs = trace.load(path, kinds)
+    red = trace.reduce_ops(ops, spans, runs, trace.step_module(hlo_text),
+                           n_devices)
     return Layers(red, hlo_scopes(hlo_text), kinds,
-                  [s for s in spans if s.name.startswith(TRAIN_PREFIX)
-                   and s.end > t0 and s.start < t1],
+                  [s for s in spans if s.name.startswith(TRAIN_PREFIX)],
                   idle_gaps(ops, spans, red))
 
 
@@ -232,16 +209,16 @@ def main(argv=None) -> int:
     ap.add_argument("trace", help="the .xplane.pb of a traced run")
     ap.add_argument("hlo", help="the traced step's compiled HLO text "
                                 "(compiled.as_text()), optionally gzipped")
-    ap.add_argument("--steps", type=int, required=True,
-                    help="the number of traced steps in the window")
     args = ap.parse_args(argv)
     opener = gzip.open if args.hlo.endswith(".gz") else open
     with opener(args.hlo, "rt") as f:
         hlo_text = f.read()
     lay = reduce(args.trace, hlo_text)
-    print(json.dumps({"readings": lay.readings(args.steps),
+    steps = lay.red.steps
+    print(json.dumps({"steps": steps, "window_s": lay.red.window_s,
+                      "readings": lay.readings(),
                       "unscoped_share": lay.unscoped_share,
-                      "by_scope_ms": {k: 1e3 * v / args.steps for k, v in
+                      "by_scope_ms": {k: 1e3 * v / steps for k, v in
                                       sorted(lay.by_scope().items())},
                       "breakdown": lay.breakdown()}, indent=1))
     return 0

@@ -5,7 +5,7 @@ from pathlib import Path
 import pytest
 
 from bench import trace
-from bench.trace import Op, Span
+from bench.trace import Op, Run, Span
 
 DATA = Path(__file__).resolve().parent / "data"
 
@@ -19,9 +19,16 @@ def test_union_subtract_and_clip():
     assert trace.length([(0, 1.5), (2, 3)]) == 2.5
 
 
+STEP = "jit_step"
+
+
 def _made_up():
     spans = [Span("bench.batch", 0.0, 0.1), Span("bench.step", 0.1, 0.3),
              Span("bench.readback", 0.9, 1.0)]
+    # the step's program runs on device 0 from 0.0 and again from 1.0: the
+    # window holds one step; a run of another program does not bound it
+    runs = [Run(0, STEP, 0.0, 0.8), Run(0, "jit_other", 0.85, 0.9),
+            Run(0, STEP, 1.0, 1.5), Run(1, STEP, 0.0, 0.5)]
     ops = [
         # device 0: a convolution, a fusion over it, an all-reduce half
         # hidden under compute, and an idle gap while the host reads back
@@ -32,14 +39,17 @@ def _made_up():
         Op(1, "convolution.1", "convolution", 0.2, 0.4),
         Op(1, "all-reduce.3", "collective", 0.4, 0.5),
         Op(1, "fusion.9", "fusion", 1.2, 1.5),
+        # device 0: the step's next run, past the window
+        Op(0, "convolution.1", "convolution", 1.2, 1.5),
     ]
-    return ops, spans
+    return ops, spans, runs
 
 
 def test_reduction_of_made_up_ops():
-    ops, spans = _made_up()
-    red = trace.reduce_ops(ops, spans)
+    ops, spans, runs = _made_up()
+    red = trace.reduce_ops(ops, spans, runs, STEP)
     assert red.window == (0.0, 1.0)
+    assert red.steps == 1
     assert red.busy == {0: pytest.approx(0.6), 1: pytest.approx(0.3)}
     assert red.busy_s == pytest.approx(0.45)
     assert red.idle_share == pytest.approx(0.55)
@@ -60,21 +70,31 @@ def test_reduction_of_made_up_ops():
 
 
 def test_window_needs_the_benchmarks_spans():
-    ops, _ = _made_up()
+    """The window needs two runs of the step's program on the first device;
+    the benchmark's spans no longer bound it."""
+    ops, spans, runs = _made_up()
+    for few in ([], runs[:1], runs[1:2] + runs[3:]):
+        with pytest.raises(ValueError):
+            trace.reduce_ops(ops, spans, few, STEP)
     with pytest.raises(ValueError):
-        trace.reduce_ops(ops, [Span("bench.step", 0, 1)])
+        trace.reduce_ops(ops, spans, runs, "jit_missing")
+    assert trace.reduce_ops(ops, [], runs, STEP).window == (0.0, 1.0)
 
 
 def test_reduction_of_a_trace_recorded_on_a_v5e():
     """Three steps of a small conv step on one TPU v5e, each inside
     ``bench.batch`` / ``bench.step`` / ``bench.readback`` spans; the
     readback span sleeps 2 ms, so the device idles through it."""
-    ops, spans = trace.load(str(DATA / "tiny_conv_v5e.xplane.pb"))
+    ops, spans, runs = trace.load(str(DATA / "tiny_conv_v5e.xplane.pb"))
     assert {o.device for o in ops} == {0}
     assert all("=" not in o.name for o in ops)      # short HLO names
     assert sorted({s.name for s in spans}) == [
         "bench.batch", "bench.readback", "bench.step"]
-    red = trace.reduce_ops(ops, spans)
+    # each run of the conv step follows a run of a multiply
+    assert [r.module for r in runs] == ["jit_multiply", "jit__lambda"] * 3
+    red = trace.reduce_ops(ops, spans, runs, "jit__lambda")
+    assert red.steps == 2
+    assert red.window == (runs[1].start, runs[5].start)
     assert 0 < red.busy_s < red.window_s
     assert 0 < red.idle_share < 1
     gaps = dict(sorted(red.gaps, key=lambda g: g[1]))
@@ -96,6 +116,7 @@ def test_hlo_kinds_finds_convolutions_inside_fusions():
 
     text = jax.jit(jax.grad(f)).lower(jnp.ones((2, 8, 8, 4)),
                                       jnp.ones((3, 3, 4, 8))).compile().as_text()
+    assert trace.step_module(text) == "jit_f"
     kinds = trace.hlo_kinds(text)
     assert list(kinds.values()).count("convolution") >= 2   # fwd and dgrad
     assert trace.op_name("%fusion.3 = f32[2] fusion(f32[2] %a), kind=kLoop") \

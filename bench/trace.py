@@ -3,13 +3,21 @@ exposed collective time, and idle gaps attributed to the host's spans.
 
 Reads the ``.xplane.pb`` that ``jax.profiler`` writes, with nothing but
 JAX's ``ProfileData``. Device operations are the events of the ``XLA Ops``
-line of each ``/device:TPU:<n>`` plane; host spans are the benchmark's
-``bench.*`` annotations on the host plane. Both are on one clock. The
-window is the span of the traced steps: from the first ``bench.batch`` to
-the end of the last ``bench.readback``.
+line of each ``/device:TPU:<n>`` plane, and runs of compiled programs those
+of its ``XLA Modules`` line; host spans are the benchmark's ``bench.*`` and
+the program's ``train.*`` annotations on the host plane.
+
+The window is bounded by the runs of the traced step's program (the module
+that its compiled HLO names) on the first device: from the start of its
+first whole run in the trace to the start of its last. The steps in the
+window are the runs that start inside it, each with the idle time that
+follows it, so the window holds whole steps however far ahead of the
+device the host dispatches; a run that was under way when the trace
+started is left out. Host spans only name the idle gaps.
 """
 from __future__ import annotations
 
+import bisect
 import glob
 import re
 from dataclasses import dataclass, field
@@ -18,8 +26,10 @@ import numpy as np
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 HOST_PLANE = "/host:CPU"
 SPAN_PREFIX = "bench."
+TRAIN_PREFIX = "train."
 COLLECTIVE = re.compile(r"all-reduce|all-gather|reduce-scatter|"
                         r"collective-permute|all-to-all|send|recv")
 CONVOLUTION = re.compile(r"convolution")
@@ -37,6 +47,16 @@ class Op:
 @dataclass
 class Span:
     name: str
+    start: float
+    end: float
+
+
+@dataclass
+class Run:
+    """One run of a compiled program on a device."""
+
+    device: int
+    module: str           # ``jit_train_step``: the HLO module's name
     start: float
     end: float
 
@@ -81,6 +101,15 @@ def hlo_kinds(hlo_text: str) -> dict:
     return kinds
 
 
+def step_module(hlo_text: str) -> str:
+    """The module name of a compiled program's HLO text (``HloModule
+    jit_train_step, ...``), which names its runs in the trace."""
+    m = re.match(r"\s*HloModule\s+([\w.\-]+)", hlo_text)
+    if m is None:
+        raise ValueError("the HLO text names no module")
+    return m.group(1)
+
+
 def op_name(event_name: str) -> str:
     """``fusion.12`` of a TPU op event named by its whole HLO instruction,
     ``%fusion.12 = f32[...] fusion(...), ...``; other names as they are."""
@@ -109,30 +138,38 @@ def is_convolution(op: Op) -> bool:
     return op.category == "convolution"
 
 
-def load(path: str, kinds: dict | None = None) -> tuple[list[Op], list[Span]]:
+def load(path: str, kinds: dict | None = None
+         ) -> tuple[list[Op], list[Span], list[Run]]:
+    """The device ops, the ``bench.*`` and ``train.*`` host spans in order
+    of their start, and the program runs of a trace."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
-    ops, spans = [], []
+    ops, spans, runs = [], [], []
     for plane in pd.planes:
         m = DEVICE_PLANE.match(plane.name)
         if m:
             dev = int(m.group(1))
             for line in plane.lines:
-                if line.name != OPS_LINE:
+                if line.name not in (OPS_LINE, MODULES_LINE):
                     continue
                 for ev in line.events:
-                    name = op_name(ev.name)
                     t0 = ev.start_ns * 1e-9
-                    ops.append(Op(dev, name, category(name, kinds),
-                                  t0, t0 + ev.duration_ns * 1e-9))
+                    t1 = t0 + ev.duration_ns * 1e-9
+                    if line.name == MODULES_LINE:
+                        # ``jit_train_step(<fingerprint>)``
+                        runs.append(Run(dev, ev.name.split("(")[0], t0, t1))
+                    else:
+                        name = op_name(ev.name)
+                        ops.append(Op(dev, name, category(name, kinds),
+                                      t0, t1))
         elif plane.name == HOST_PLANE:
             for line in plane.lines:
                 for ev in line.events:
-                    if ev.name.startswith(SPAN_PREFIX):
+                    if ev.name.startswith((SPAN_PREFIX, TRAIN_PREFIX)):
                         t0 = ev.start_ns * 1e-9
                         spans.append(Span(ev.name, t0,
                                           t0 + ev.duration_ns * 1e-9))
-    return ops, spans
+    return ops, sorted(spans, key=lambda s: s.start), runs
 
 
 def union(intervals) -> list[tuple[float, float]]:
@@ -179,6 +216,7 @@ class Reduction:
     """Per-device totals over the traced window."""
 
     window: tuple[float, float]
+    steps: int                                        # runs in the window
     devices: list[int]
     busy: dict = field(default_factory=dict)          # device -> seconds
     by_op: dict = field(default_factory=dict)         # op name -> s/device
@@ -206,13 +244,39 @@ class Reduction:
                 "idle_gaps": [[n, s] for n, s in gaps]}
 
 
-def window_of(spans: list[Span]) -> tuple[float, float]:
-    starts = [s.start for s in spans if s.name == "bench.batch"]
-    ends = [s.end for s in spans if s.name == "bench.readback"]
-    if not starts or not ends:
-        raise ValueError("the trace holds no bench.batch / bench.readback "
-                         "spans to bound its window")
-    return min(starts), max(ends)
+def whole_runs(runs: list[Run], ops: list[Op], module: str, device: int
+               ) -> list[Run]:
+    """The runs of ``module`` on ``device`` that the trace holds whole, in
+    order. A run already under way when the trace started is recorded from
+    that moment: it begins with another op than the runs that started
+    inside the trace, the last one among them, and is left out."""
+    mine = sorted((r for r in runs
+                   if r.module == module and r.device == device),
+                  key=lambda r: r.start)
+    starts = sorted((o.start, o.name) for o in ops if o.device == device)
+
+    def first_op(run: Run) -> str | None:
+        i = bisect.bisect_left(starts, (run.start,))
+        if i < len(starts) and starts[i][0] <= run.end:
+            return starts[i][1]
+        return None
+
+    if len(mine) > 1 and first_op(mine[0]) != first_op(mine[-1]):
+        return mine[1:]
+    return mine
+
+
+def window_of(runs: list[Run], ops: list[Op], module: str, device: int
+              ) -> tuple[tuple[float, float], int]:
+    """The window of the traced steps and the number of steps in it: from
+    the start of the first whole run of ``module`` on ``device``
+    (``whole_runs``) to the start of its last; the steps are the runs that
+    start inside."""
+    starts = [r.start for r in whole_runs(runs, ops, module, device)]
+    if len(starts) < 2:
+        raise ValueError(f"the trace holds {len(starts)} whole runs of "
+                         f"{module} on device {device}; its window needs two")
+    return (starts[0], starts[-1]), len(starts) - 1
 
 
 def host_span_over(spans: list[Span], t0: float, t1: float) -> str:
@@ -230,15 +294,19 @@ def host_span_over(spans: list[Span], t0: float, t1: float) -> str:
     return max(share, key=share.get)
 
 
-def reduce_ops(ops: list[Op], spans: list[Span],
-               n_devices: int | None = None) -> Reduction:
-    t0, t1 = window_of(spans)
+def reduce_ops(ops: list[Op], spans: list[Span], runs: list[Run],
+               module: str, n_devices: int | None = None) -> Reduction:
+    """Busy and idle time, op time and gaps over the window of ``module``'s
+    runs (``window_of``), per device; gaps named by the ``bench.*`` spans
+    (``host_span_over``)."""
     devices = sorted({o.device for o in ops})
     if n_devices is not None:
         devices = devices[:n_devices]
     if not devices:
         raise ValueError("the trace holds no device operations")
-    red = Reduction((t0, t1), devices)
+    (t0, t1), steps = window_of(runs, ops, module, devices[0])
+    spans = [s for s in spans if s.name.startswith(SPAN_PREFIX)]
+    red = Reduction((t0, t1), steps, devices)
     nd = len(devices)
     for d in devices:
         mine = [o for o in ops if o.device == d and o.end > t0 and o.start < t1]
@@ -262,7 +330,9 @@ def reduce_ops(ops: list[Op], spans: list[Span],
     return red
 
 
-def reduce(path: str, n_devices: int | None = None,
-           hlo_text: str | None = None) -> Reduction:
-    ops, spans = load(path, hlo_kinds(hlo_text) if hlo_text else None)
-    return reduce_ops(ops, spans, n_devices)
+def reduce(path: str, hlo_text: str,
+           n_devices: int | None = None) -> Reduction:
+    """``reduce_ops`` of a trace, over the runs of the step whose compiled
+    HLO text is ``hlo_text``."""
+    ops, spans, runs = load(path, hlo_kinds(hlo_text))
+    return reduce_ops(ops, spans, runs, step_module(hlo_text), n_devices)

@@ -13,7 +13,9 @@ run_with_recovery`` drives the window with ``Trainer.step``, as
 - a few more warm steps that size the window to ``--seconds``;
 - the window: from the first timed step's dispatch to the last step's
   completion; the loop's closing save falls after it;
-- with tracing on, a few more steps under the profiler;
+- with tracing on, a few more steps under the profiler, reduced over the
+  whole steps that the runs of the compiled step bound (``bench/trace.py``)
+  and read with the program's scopes and spans (``bench/scopes.py``);
 - the compiled step's memory as the compiler reports it;
 - once the program's state is freed, the plain reference over steps 0 to 2
   at the configuration's matmul precision, and the comparison that decides
@@ -43,6 +45,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from . import scopes
 from . import trace as trace_mod
 from .cell import Cell, metric_reader
 
@@ -406,19 +409,24 @@ def drive(cell: Cell, devices, *, seed: int, seconds: float, trace: bool,
                   "failed": int(sum(not math.isfinite(x)
                                     for x in window_losses))}
         if trace:
-            pb = trace_mod.find_xplane(trace_dir)
-            red = trace_mod.reduce(pb, n_devices=cell.chips, hlo_text=hlo)
+            lay = scopes.reduce(trace_mod.find_xplane(trace_dir), hlo,
+                                n_devices=cell.chips)
+            red = lay.red
+            log(f"traced window: {red.steps} steps in {red.window_s:.6f} s, "
+                f"device busy {red.busy_s:.6f} s")
             ctx = SimpleNamespace(
-                cell=cell, arch=arch, trace=red, peak=peak, flops=flops,
-                model=m, batch=batch, chips=cell.chips, steps=n_traced,
-                step_flops=step_flops, mean_step_s=window / n, itemsize=2)
+                cell=cell, arch=arch, trace=red, layers=lay, peak=peak,
+                flops=flops, model=m, batch=batch, chips=cell.chips,
+                steps=red.steps, step_flops=step_flops,
+                mean_step_s=window / n, itemsize=2)
             metrics = {}
             for spec in cell.per_layer:
                 v = metric_reader(spec["name"])(ctx)
                 if v is not None:
                     metrics[spec["name"]] = {"value": v, "unit": spec["unit"]}
-            result["breakdown"] = red.breakdown()
-            device_extra = {"busy_s": red.busy_s, "window_s": red.window_s}
+            result["breakdown"] = lay.breakdown()
+            device_extra = {"busy_s": red.busy_s, "window_s": red.window_s,
+                            "window_steps": red.steps}
         else:
             metrics = {spec["name"]: {"value": end_to_end[spec["name"]],
                                       "unit": spec["unit"]}
