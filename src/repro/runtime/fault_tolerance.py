@@ -10,7 +10,12 @@ tests/test_chaos.py for the full elastic path):
   addressable so the resumed stream is identical. The restart budget counts
   *consecutive* failures: forward progress (a checkpoint newer than the one
   seen at the previous failure) resets it, so spaced transient faults over a
-  long run never exhaust it while a crash loop still aborts.
+  long run never exhaust it while a crash loop still aborts. The loop keeps
+  one step in flight: step s+1 is dispatched before the host waits on step
+  s's loss, so the host's per-step work overlaps the device's. It drains
+  (finishes the step in flight before dispatching another) before each
+  save, at the last step, before an alert that may escalate, and before
+  handling a fault of the next step.
 * **straggler mitigation** — ``StepTimer`` keeps a ring buffer of step times;
   a step slower than ``threshold × median`` raises a StragglerAlert (the
   outlier sample stays OUT of the window, so one slow step cannot inflate
@@ -143,12 +148,28 @@ def run_with_recovery(step_fn, state, loader, ckpt: Checkpointer, *,
     re-tuned plan's shardings; by default the live state is the skeleton
     and leaves land wherever ``device_put`` defaults.
 
+    The loop keeps one step in flight: in steady state it fetches step
+    s+1's batch and dispatches s+1 on step s's output state, then waits on
+    step s's loss and runs the timer and ``on_metrics`` for step s, so the
+    device runs s+1 while the host does its per-step work. It drains —
+    waits on the step in flight and finishes it before dispatching another
+    — where step s's output state is needed or no step s+1 follows: before
+    each save (the state is donated into the next step), at the last step,
+    and when step s's alert may escalate (``strikes`` one short of
+    ``straggler_patience``). A fault of step s+1 (``inject``, an injected
+    failure or an error while dispatching it) is handled once step s has
+    finished; an error in step s's own wait discards s+1 and takes the
+    restart path. The timer's step time runs from the end of the previous
+    step's wait to the end of this one's, or from the step's batch where
+    the loop drained before it.
+
     Each step opens profiler spans, in order: ``train.batch`` (the
     loader), ``train.dispatch`` (the call into ``step_fn``), ``train.wait``
     (until the loss is ready), ``train.metrics`` (``on_metrics``), and
-    ``train.checkpoint`` around each save and restore. They cost about a
-    microsecond without an active ``jax.profiler`` session, and share the
-    device trace's clock with one.
+    ``train.checkpoint`` around each save and restore; step s+1's batch and
+    dispatch come before step s's wait. They cost about a microsecond
+    without an active ``jax.profiler`` session, and share the device
+    trace's clock with one.
     """
     timer = timer or StepTimer()
     step = start_step
@@ -160,20 +181,45 @@ def run_with_recovery(step_fn, state, loader, ckpt: Checkpointer, *,
                   else set(int(s) for s in inject_failure_at or ()))
     fired: set[int] = set()
     strikes = 0
+
+    def dispatch(s, state):
+        """Step ``s``'s hook, batch and dispatch: the output state and the
+        step in flight, ``(metrics, t0, fake_dt)``."""
+        fake_dt = inject(s) if inject is not None else None
+        t0 = time.perf_counter()
+        with TraceAnnotation("train.batch"):
+            batch = loader.batch_at(s)
+        if s in fail_steps and s not in fired:
+            fired.add(s)
+            raise RuntimeError(f"injected node failure at step {s}")
+        with TraceAnnotation("train.dispatch"):
+            state, metrics = step_fn(state, batch)
+        return state, (metrics, t0, fake_dt)
+
+    inflight = None          # step `step`, dispatched, not yet waited on
     while step < n_steps:
         try:
-            fake_dt = inject(step) if inject is not None else None
-            t0 = time.perf_counter()
-            with TraceAnnotation("train.batch"):
-                batch = loader.batch_at(step)
-            if step in fail_steps and step not in fired:
-                fired.add(step)
-                raise RuntimeError(f"injected node failure at step {step}")
-            with TraceAnnotation("train.dispatch"):
-                state, metrics = step_fn(state, batch)
+            if inflight is None:
+                state, inflight = dispatch(step, state)
+            # drain where step `step`'s output state is saved or no step
+            # follows it; otherwise dispatch the next step on that state
+            nxt = step + 1
+            drain = (nxt >= n_steps or nxt % ckpt_every == 0
+                     or (straggler_patience is not None
+                         and strikes >= straggler_patience - 1))
+            ahead = fault = None
+            if not drain:
+                try:
+                    state, ahead = dispatch(nxt, state)
+                except Exception as e:  # noqa: BLE001 — raised after `step`
+                    fault = e
+            metrics, t0, fake_dt = inflight
             with TraceAnnotation("train.wait"):
                 jax.block_until_ready(metrics["loss"])
-            dt = fake_dt if fake_dt is not None else time.perf_counter() - t0
+            waited = time.perf_counter()
+            dt = fake_dt if fake_dt is not None else waited - t0
+            if ahead is not None:
+                ahead = (ahead[0], waited, ahead[2])
             escalate = None
             try:
                 timer.observe(step, dt)
@@ -190,6 +236,7 @@ def run_with_recovery(step_fn, state, loader, ckpt: Checkpointer, *,
                 with TraceAnnotation("train.metrics"):
                     on_metrics(step, metrics)
             step += 1
+            inflight = ahead
             if escalate is not None:
                 # graceful: the state is intact, persist it before leaving
                 with TraceAnnotation("train.checkpoint"):
@@ -202,9 +249,12 @@ def run_with_recovery(step_fn, state, loader, ckpt: Checkpointer, *,
             if step % ckpt_every == 0:
                 with TraceAnnotation("train.checkpoint"):
                     ckpt.save(state, step, blocking=not async_ckpt)
+            if fault is not None:
+                raise fault
         except (StragglerAlert, SliceLost):
             raise
         except Exception as e:  # noqa: BLE001 — restart path
+            inflight = None      # a step dispatched ahead is discarded
             ckpt.wait()          # an in-flight async save may still commit
             latest = ckpt.latest_step()
             key = -1 if latest is None else latest

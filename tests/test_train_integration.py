@@ -153,3 +153,137 @@ def test_grad_accumulation_matches_full_batch():
     jax.tree.map(lambda x, y: np.testing.assert_allclose(x, y, rtol=2e-5,
                                                          atol=2e-5),
                  a["params"], b["params"])
+
+
+# --- the loop keeps one step in flight -------------------------------------
+
+class _Recorder:
+    """A loader that logs each ``batch_at`` into a shared event list."""
+
+    def __init__(self, loader, events):
+        self.loader, self.events = loader, events
+
+    def batch_at(self, step):
+        self.events.append(("batch", step))
+        return self.loader.batch_at(step)
+
+
+def donating_setup(seed=0):
+    """``setup``'s step with the state donated, as the trainers build it:
+    a save of a state already donated into the next step would fail."""
+    model = tiny_lm()
+    opt = OptimizerConfig(lr=1e-2, name="adamw", zero1=False)
+    step = jax.jit(make_train_step(model, opt, NULL_CTX, attn_impl="plain",
+                                   scan_layers=False, remat=False),
+                   donate_argnums=0)
+    state = tree_init(train_state_spec(model, opt), jax.random.PRNGKey(seed))
+    return step, state, ShardedLoader(DataConfig("lm", batch=8, seq_len=32,
+                                                 vocab=V))
+
+
+def plain_run(n_steps):
+    """Plain stepping: the losses and the host state after each step."""
+    step, state, loader = donating_setup()
+    losses, states = {}, {0: jax.device_get(state)}
+    for t in range(n_steps):
+        state, m = step(state, loader.batch_at(t))
+        losses[t] = float(m["loss"])
+        states[t + 1] = jax.device_get(state)
+    return losses, states
+
+
+def _assert_trees_equal(a, b):
+    jax.tree.map(np.testing.assert_array_equal, a, b)
+
+
+def test_next_batch_is_fetched_before_this_steps_metrics(tmp_path):
+    """Step s+1's batch comes before step s's ``on_metrics`` except where
+    the loop drains: before each save (ckpt_every 5) and at the last step,
+    where step s's metrics come first."""
+    step, state, loader = donating_setup()
+    events = []
+    n = 12
+    run_with_recovery(step, state, _Recorder(loader, events),
+                      Checkpointer(tmp_path), n_steps=n, ckpt_every=5,
+                      async_ckpt=False,
+                      on_metrics=lambda s, m: events.append(("metrics", s)))
+    assert [e for e in events if e[0] == "metrics"] == \
+        [("metrics", s) for s in range(n)]
+    assert [e for e in events if e[0] == "batch"] == \
+        [("batch", s) for s in range(n)]
+    drains = {4, 9}          # the step before the saves at 5 and 10
+    for s in range(n - 1):
+        fetched = events.index(("batch", s + 1))
+        reported = events.index(("metrics", s))
+        assert (reported < fetched) == (s in drains), (s, events)
+
+
+@pytest.mark.parametrize("async_ckpt", [False, True])
+def test_checkpoints_hold_the_state_after_exactly_k_steps(tmp_path,
+                                                          async_ckpt):
+    """Saves at 5, 10 and 15 hold plain stepping's state bit for bit, and
+    ``on_metrics`` sees plain stepping's losses, with the state donated
+    into the step dispatched ahead."""
+    ref_losses, ref_states = plain_run(15)
+    step, state, loader = donating_setup()
+    ck = Checkpointer(tmp_path, keep=10)
+    losses = {}
+    final, nstep = run_with_recovery(
+        step, state, loader, ck, n_steps=15, ckpt_every=5,
+        async_ckpt=async_ckpt,
+        on_metrics=lambda s, m: losses.__setitem__(s, float(m["loss"])))
+    assert nstep == 15 and ck.completed_steps() == [5, 10, 15]
+    assert losses == ref_losses
+    for k in (5, 10, 15):
+        restored, s0 = ck.restore(ref_states[k], step=k)
+        assert s0 == k
+        _assert_trees_equal(restored, ref_states[k])
+    _assert_trees_equal(jax.device_get(final), ref_states[15])
+
+
+def test_injected_failures_replay_to_the_faultless_trajectory(tmp_path):
+    """Failures at 7 and 13 fire while the step before each is in flight;
+    that step is finished first, then the restart path replays from the
+    checkpoint: losses and final params equal a run without faults."""
+    ref_losses, ref_states = plain_run(16)
+    step, state, loader = donating_setup()
+    events, losses = [], {}
+
+    def on_metrics(s, m):
+        events.append(("metrics", s))
+        losses[s] = float(m["loss"])
+
+    final, nstep = run_with_recovery(
+        step, state, _Recorder(loader, events), Checkpointer(tmp_path),
+        n_steps=16, ckpt_every=5, async_ckpt=False,
+        inject_failure_at=(7, 13), on_metrics=on_metrics)
+    assert nstep == 16
+    # step 6 was in flight when step 7's fault fired, and finished first
+    assert events.index(("batch", 7)) < events.index(("metrics", 6))
+    assert events.count(("metrics", 6)) == 2      # replayed from step 5
+    assert losses == ref_losses
+    _assert_trees_equal(jax.device_get(final["params"]),
+                        ref_states[16]["params"])
+
+
+def test_straggler_escalation_saves_the_state_after_the_straggling_step(
+        tmp_path):
+    """Simulated stragglers at 9 and 10 exhaust a patience of 2: the loop
+    drains before step 11, saves the state after 11 steps and raises
+    ``SliceLost`` at 11, with nothing dispatched past it."""
+    from repro.runtime.fault_tolerance import SliceLost
+    _, ref_states = plain_run(11)
+    step, state, loader = donating_setup()
+    events = []
+    ck = Checkpointer(tmp_path)
+    with pytest.raises(SliceLost) as info:
+        run_with_recovery(
+            step, state, _Recorder(loader, events), ck, n_steps=20,
+            ckpt_every=100, async_ckpt=False, straggler_patience=2,
+            inject=lambda s: 9.9 if s in (9, 10) else 0.01,
+            on_metrics=lambda s, m: events.append(("metrics", s)))
+    assert info.value.cause == "straggler"
+    assert info.value.step == 11 and ck.latest_step() == 11
+    assert max(s for kind, s in events if kind == "batch") == 10
+    restored, _ = ck.restore(ref_states[11])
+    _assert_trees_equal(restored, ref_states[11])
