@@ -1,5 +1,6 @@
 """The benchmark's harness on the CPU: refusal without a chip, resolution
 of every cell by name, names and units, and the resident batches."""
+import importlib
 import json
 import os
 import re
@@ -91,17 +92,18 @@ def test_unknown_device_has_no_peaks():
 
 @pytest.mark.parametrize("name", [c["name"] for c in SPEC["configs"]])
 def test_reference_weights_fit_the_program_model(name):
-    """The benchmark's weights have the program's parameter tree: same
-    leaves, same shapes."""
+    """The benchmark's weights, from the reference of the configuration's
+    family, have the program's parameter tree: same leaves, same shapes."""
     from repro.configs import get_config
     from repro.launch.build import build_model
     entry = next(c for c in SPEC["configs"] if c["name"] == name)
     config = json.loads((ROOT / entry["file"]).read_text())
+    family = importlib.import_module(f"bench.reference.{config['family']}")
     arch = train.register_config(name, config)
     spec = build_model(get_config(arch)).params_spec()
     want = jax.tree.map(lambda s: tuple(s.shape), spec,
                         is_leaf=lambda s: hasattr(s, "axes"))
-    got = jax.eval_shape(partial(ref.init_params, m=config["model"]),
+    got = jax.eval_shape(partial(family.init_params, m=config["model"]),
                          jax.random.key(0))
     assert jax.tree.map(lambda a: a.shape, got) == want
 
